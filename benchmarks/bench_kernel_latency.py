@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit, rope_structured_keys, time_fn
 from repro.core.quantizers import QuantConfig, encode_polar_keys
 from repro.core import lut as lut_mod
+from repro.launch.chips import chip_peaks
 from repro.core.quantizers import decode_polar_keys
 
 # Llama-3.1-8B attention geometry (paper §4.2): 32 q heads, 8 kv heads, d=128
@@ -186,7 +187,8 @@ def run() -> None:
             mth = {"fp16": "fp16"}.get(name, "polar44")
             hbm = hbm_bytes_per_layer(t, b, mth, g)
             emit(f"qk_latency/b{b}_t{t}/{name}", us,
-                 f"tpu_hbm_bytes={hbm};v5e_mem_us={hbm / 819e9 * 1e6:.2f}")
+                 f"tpu_hbm_bytes={hbm};v5e_mem_us="
+                 f"{hbm / chip_peaks('TPU v5 lite').hbm_bw * 1e6:.2f}")
         # paper Table 4 headline: byte ratio fp16 / polar
         ratio = hbm_bytes_per_layer(t, b, "fp16") / hbm_bytes_per_layer(
             t, b, "polar44", g)

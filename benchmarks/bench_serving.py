@@ -736,32 +736,32 @@ def run_adversarial(cfg, params, args) -> dict:
     return out
 
 
-def run_mesh_arm(args) -> int:
-    """Internal ``--mesh-arm`` mode: ONE continuous-batching arm on a
-    (data x model) mesh, minimal JSON record to ``--json``.
+def _parse_mesh_shape(spec: str) -> tuple[int, int]:
+    try:
+        d, m = (int(x) for x in spec.split("x"))
+    except ValueError:
+        raise SystemExit(f"bad --mesh-shape {spec!r}; "
+                         "expected e.g. '1x2' (data x model)")
+    return d, m
 
-    Runs in its own process so the driver's
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` takes effect —
-    device forcing is a process-level switch that must precede jax init.
-    """
+
+def mesh_arm_record(args, d: int, m: int, devices=None) -> dict:
+    """ONE continuous-batching arm on a (data x model) mesh over
+    ``devices`` (default: every device of the process) -> a minimal
+    record, greedy-output digest included."""
     import hashlib
     import json
 
-    try:
-        d, m = (int(x) for x in args.mesh_shape.split("x"))
-    except ValueError:
-        raise SystemExit(f"bad --mesh-shape {args.mesh_shape!r}; "
-                         "expected e.g. '1x2' (data x model)")
     from repro.launch.mesh import make_mesh
-    mesh = make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"), devices=devices)
     cfg = reduce_for_smoke(get_config(args.arch))
     params = get_model(cfg).init(jax.random.PRNGKey(0))
     res = run_cb(cfg, params, args, backend=args.backend,
                  max_len=args.max_len, mesh=mesh)
     outs = sorted((r.rid, list(r.out_tokens))
                   for r in res.get("requests", []))
-    rec = {
-        "devices": jax.device_count(),
+    return {
+        "devices": d * m,
         "mesh": {"data": d, "model": m},
         "head_sharded": cfg.num_kv_heads % m == 0,
         "tokens_per_s": res["tokens_per_s"],
@@ -774,62 +774,91 @@ def run_mesh_arm(args) -> int:
         "outputs_digest": hashlib.sha256(
             json.dumps(outs).encode()).hexdigest()[:16],
     }
+
+
+def run_mesh_arm(args) -> int:
+    """Internal ``--mesh-arm`` mode: one mesh arm, its record to ``--json``.
+
+    The CPU sweep runs it in its own process so the driver's
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` takes effect —
+    device forcing is a process-level switch that must precede jax init.
+    """
+    import json
+
+    d, m = _parse_mesh_shape(args.mesh_shape)
+    rec = mesh_arm_record(args, d, m)
     with open(args.json, "w") as f:
         json.dump(rec, f, indent=2, sort_keys=True)
     return 0
 
 
-def run_mesh_sweep(args) -> dict:
-    """Multi-device serving sweep: tokens/s + TTFT p50/p95 vs device count
-    at fixed total pool bytes (same slots/max_len/num_pages every arm; only
-    the device count — and thus per-device pool bytes, where kv_heads
-    divides the model axis — changes).
-
-    Each count N runs :func:`run_mesh_arm` in a subprocess under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` with a (1, N)
-    (data x model) mesh. On CPU the forced "devices" are host threads, so
-    this measures sharding *orchestration* overhead and correctness, not a
-    speedup — the numbers keep the multi-device decode path tracked across
-    PRs. Greedy-output digests must agree across arms.
-    """
+def _forced_host_arm(args, n: int) -> dict:
+    """Run one CPU mesh arm in a child process with ``n`` forced host
+    devices; a failed child fails the sweep."""
     import json
     import os
     import subprocess
     import sys
     import tempfile
 
-    arms = []
-    for n in args.mesh_sweep:
-        env = dict(os.environ)
-        flags = [f for f in env.get("XLA_FLAGS", "").split()
-                 if not f.startswith("--xla_force_host_platform")]
-        flags.append(f"--xla_force_host_platform_device_count={n}")
-        env["XLA_FLAGS"] = " ".join(flags)
-        env["PYTHONPATH"] = "src" + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        fd, out_path = tempfile.mkstemp(suffix=".json")
-        os.close(fd)
-        cmd = [sys.executable, "-m", "benchmarks.bench_serving",
-               "--mesh-arm", "--mesh-shape", f"1x{n}",
-               "--arch", args.arch,
-               "--requests", str(args.requests), "--rate", str(args.rate),
-               "--slots", str(args.slots), "--max-len", str(args.max_len),
-               "--num-pages", str(args.num_pages),
-               "--prompt-lo", str(args.prompt_lo),
-               "--prompt-hi", str(args.prompt_hi),
-               "--out-lo", str(args.out_lo), "--out-hi", str(args.out_hi),
-               "--seed", str(args.seed), "--backend", args.backend,
-               "--json", out_path]
+    env = dict(os.environ)
+    flags = [f for f in env.get("XLA_FLAGS", "").split()
+             if not f.startswith("--xla_force_host_platform")]
+    flags.append(f"--xla_force_host_platform_device_count={n}")
+    env["XLA_FLAGS"] = " ".join(flags)
+    env["PYTHONPATH"] = "src" + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    fd, out_path = tempfile.mkstemp(suffix=".json")
+    os.close(fd)
+    cmd = [sys.executable, "-m", "benchmarks.bench_serving",
+           "--mesh-arm", "--mesh-shape", f"1x{n}",
+           "--arch", args.arch,
+           "--requests", str(args.requests), "--rate", str(args.rate),
+           "--slots", str(args.slots), "--max-len", str(args.max_len),
+           "--num-pages", str(args.num_pages),
+           "--prompt-lo", str(args.prompt_lo),
+           "--prompt-hi", str(args.prompt_hi),
+           "--out-lo", str(args.out_lo), "--out-hi", str(args.out_hi),
+           "--seed", str(args.seed), "--backend", args.backend,
+           "--json", out_path]
+    try:
         proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
                               timeout=1800)
         if proc.returncode != 0:
-            print(f"mesh-sweep arm devices={n} FAILED\n"
-                  f"{proc.stdout}\n{proc.stderr}")
-            os.unlink(out_path)
-            continue
+            raise RuntimeError(f"mesh-sweep arm devices={n} failed "
+                               f"(exit {proc.returncode})\n"
+                               f"{proc.stdout}\n{proc.stderr}")
         with open(out_path) as f:
-            arm = json.load(f)
+            return json.load(f)
+    finally:
         os.unlink(out_path)
+
+
+def run_mesh_sweep(args) -> dict:
+    """Multi-device serving sweep: tokens/s + TTFT p50/p95 vs device count
+    at fixed total pool bytes (same slots/max_len/num_pages every arm; only
+    the device count — and thus per-device pool bytes, where kv_heads
+    divides the model axis — changes). Each count N runs one arm on a
+    (1, N) (data x model) mesh, and a failed arm fails the sweep.
+
+    On a TPU host each arm builds its mesh from the first N chips in this
+    process: a chip belongs to one process at a time, and this one already
+    holds them all. On CPU each arm runs :func:`run_mesh_arm` in a child
+    process under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``;
+    the forced "devices" are host threads, so those arms measure sharding
+    *orchestration* overhead and correctness, not a speedup. Greedy-output
+    digests must agree across arms.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    arms = []
+    for n in args.mesh_sweep:
+        if on_tpu:
+            if n > jax.device_count():
+                raise ValueError(f"mesh-sweep arm devices={n} but only "
+                                 f"{jax.device_count()} chips are present")
+            arm = mesh_arm_record(args, 1, n, devices=jax.devices()[:n])
+        else:
+            arm = _forced_host_arm(args, n)
         arms.append(arm)
         print(f"mesh devices={n:2d} "
               f"head_sharded={str(arm['head_sharded']):5s} "

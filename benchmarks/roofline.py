@@ -3,9 +3,11 @@
 Reads artifacts/dryrun/<mesh>/<arch>/<shape>.json (produced by
 repro.launch.dryrun) and derives, per cell:
 
-  compute_s    = HLO_FLOPs_per_device / peak_FLOPs          (197 TF bf16)
-  memory_s     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-  collective_s = collective_wire_bytes_per_device / ICI_bw  (~50 GB/s)
+  compute_s    = HLO_FLOPs_per_device / peak_FLOPs
+  memory_s     = HLO_bytes_per_device / HBM_bw
+  collective_s = collective_wire_bytes_per_device / ICI_bw
+
+with the target chip's published peaks from ``repro.launch.chips``.
 
 plus MODEL_FLOPS (analytic 6ND / 2ND) vs compiled-FLOPs utilization.
 ``compiled.cost_analysis()`` on the SPMD module reports per-device values;
@@ -19,7 +21,7 @@ import os
 from typing import Optional
 
 from repro.configs import SHAPES, get_config
-from repro.launch.dryrun_lib import HBM_BW, ICI_BW, PEAK_FLOPS, roofline_terms
+from repro.launch.dryrun_lib import TARGET, roofline_terms
 
 ART = os.environ.get("DRYRUN_ARTIFACTS", "artifacts/dryrun")
 
@@ -118,8 +120,9 @@ _MITIGATE = {
 
 def print_table(mesh_tag: str = "single_16x16", devices: int = 256) -> None:
     rows = analyze(mesh_tag, devices)
-    print(f"# Roofline [{mesh_tag}] peak={PEAK_FLOPS/1e12:.0f}TF "
-          f"hbm={HBM_BW/1e9:.0f}GB/s ici={ICI_BW/1e9:.0f}GB/s")
+    print(f"# Roofline [{mesh_tag}] peak={TARGET.bf16_flops/1e12:.0f}TF "
+          f"hbm={TARGET.hbm_bw/1e9:.0f}GB/s "
+          f"ici={TARGET.ici_bw/1e9:.0f}GB/s")
     hdr = ("arch,shape,compute_s,memory_s,collective_s,dominant,"
            "useful_ratio,roofline_frac,peak_gib,fits,mitigation")
     print(hdr)

@@ -21,6 +21,8 @@ def main() -> None:
                          "ui.perfetto.dev)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_bitwidth, bench_eviction_compat,
                             bench_group_size, bench_kernel_latency,
                             bench_kv_sensitivity, bench_quant_error,

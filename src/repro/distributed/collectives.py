@@ -56,26 +56,6 @@ def _ef_leaf(g: Array, err: Array, axis: str):
     return total / n, new_err[None]
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (check_vma / check_rep renames,
-    pre-0.5 location under jax.experimental.shard_map)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
-# public name: serving/test code reaches shard_map through this compat
-# wrapper rather than version-sniffing jax itself
-shard_map_compat = _shard_map
-
-
 # ---------------------------------------------------------------------------
 # Online-softmax stats: per-shard partials + merge collectives
 # ---------------------------------------------------------------------------
@@ -170,9 +150,10 @@ def ef_allreduce_mean(grads: Any, errors: Any, mesh: Mesh, axis: str = "dp"):
 
     outs, new_errs = [], []
     for g, e in zip(flat, flat_err):
-        fn = _shard_map(
+        fn = jax.shard_map(
             functools.partial(_ef_leaf, axis=axis), mesh=mesh,
-            in_specs=(P(axis), P(axis)), out_specs=(P(), P(axis)))
+            in_specs=(P(axis), P(axis)), out_specs=(P(), P(axis)),
+            check_vma=False)
         o, ne = fn(g, e)
         outs.append(o)
         new_errs.append(ne)

@@ -43,8 +43,7 @@ from repro.core import paged_cache as pgc
 from repro.core import quantizers as qz
 from repro.distributed import ctx
 from repro.distributed.collectives import (allgather_concat, finalize_softmax,
-                                           merge_softmax_stats, shard_map_compat,
-                                           softmax_stats)
+                                           merge_softmax_stats, softmax_stats)
 
 Array = jax.Array
 
@@ -138,11 +137,11 @@ def sharded_paged_decode_attention(cache: pgc.PagedKVCache, q: Array,
         return pgc.paged_decode_attention(c, qq, pt, scale=scale,
                                           backend=backend)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(cache_pspecs(cache, axis), P(None, axis, None),
                   P(None, None)),
-        out_specs=P(None, axis, None))
+        out_specs=P(None, axis, None), check_vma=False)
     return fn(cache, q, page_table)
 
 
@@ -165,10 +164,10 @@ def sharded_paged_prefill_attention(cache: pgc.PagedKVCache, q: Array,
                                            scale=scale, backend=backend)
 
     h4 = P(None, axis, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(cache_pspecs(cache, axis), h4, h4, h4, P(None), P(), P()),
-        out_specs=h4)
+        out_specs=h4, check_vma=False)
     return fn(cache, q, k_chunk, v_chunk, page_row,
               jnp.asarray(start, jnp.int32), jnp.asarray(chunk_len, jnp.int32))
 
@@ -344,9 +343,9 @@ def context_parallel_decode(cache: pgc.PagedKVCache, q: Array,
         out = finalize_softmax(l, acc)                  # (S, Hkv, qpk, d)
         return out.reshape(s, hq, -1).astype(qq.dtype)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda x: P(), cache), P(),
                   P(None, axis)),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     return fn(cache, q, page_table)

@@ -1,7 +1,9 @@
 """Pallas TPU kernels for PolarQuant hot spots + jnp oracles.
 
-Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
-validated on CPU with interpret=True against ``ref.py``.
+Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling). Every
+wrapper takes ``interpret`` explicitly: the CPU tests run the kernel
+bodies with interpret=True against ``ref.py``, and
+``tests/test_tpu_compile.py`` compiles them for a described v5e.
 """
 from repro.kernels.ops import (  # noqa: F401
     polar_qk_scores, polar_encode, polar_decode_attention_grouped,

@@ -76,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "k_blk", "interpret"))
 def flash_prefill(q: Array, k: Array, v: Array, *, causal: bool = True,
                   scale: float | None = None, q_blk: int = 128,
-                  k_blk: int = 128, interpret: bool = True) -> Array:
+                  k_blk: int = 128, interpret: bool) -> Array:
     """q: (B, H, Tq, d); k/v: (B, Hkv, Tk, d); returns (B, H, Tq, d).
 
     VMEM per step ~= (q_blk + 2*k_blk)*d*4 + q_blk*k_blk*4 + q_blk*d*4

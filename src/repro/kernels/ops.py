@@ -2,8 +2,8 @@
 
 ``backend``:
   * ``"pallas"``    — pl.pallas_call targeting TPU (interpret=False)
-  * ``"interpret"`` — same kernel body executed in Python on CPU (default
-                       here: this container has no TPU)
+  * ``"interpret"`` — same kernel body run by the Pallas interpreter (how
+                       the CPU test suite covers the kernel bodies)
   * ``"ref"``       — pure-jnp oracle (fastest on CPU, used inside jitted
                        serving steps and the dry-run)
 

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.polar_attention import _lut_scores_block
+from repro.kernels.polar_attention import _lut_scores_block, to_f32
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -79,7 +79,7 @@ def _paged_attn_kernel(table_ref, flushed_ref, q_ref, codes_ref, rs_ref,
     corr = jnp.exp(m_prev - m_new)
 
     if quantized_values:
-        v = (v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
+        v = (to_f32(v_ref[0, 0]) * vs_ref[0, 0].astype(jnp.float32)
              + vz_ref[0, 0].astype(jnp.float32))           # (g, d)
     else:
         v = v_ref[0, 0].astype(jnp.float32)
@@ -96,15 +96,15 @@ def _paged_attn_kernel(table_ref, flushed_ref, q_ref, codes_ref, rs_ref,
     # Final carry lands in the (s, h)-indexed output tiles on the last page;
     # intermediate writes are overwritten (n is the innermost grid dim).
     out_ref[0, 0] = acc_ref[...]
-    m_out_ref[0, 0] = m_ref[..., 0]
-    l_out_ref[0, 0] = l_ref[..., 0]
+    m_out_ref[0, 0] = m_ref[...]
+    l_out_ref[0, 0] = l_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("r_bits", "t_bits", "interpret"))
 def polar_paged_decode_grouped(
     q: Array, codes: Array, rs: Array, rz: Array, ts: Array, tz: Array,
     values, vscale, vzero, page_table: Array, flushed: Array, *,
-    r_bits: int = 4, t_bits: int = 4, interpret: bool = True,
+    r_bits: int = 4, t_bits: int = 4, interpret: bool,
 ):
     """Fused flash-decode over the grouped segment, straight off the pools.
 
@@ -162,10 +162,12 @@ def polar_paged_decode_grouped(
             stat_spec, stat_spec, stat_spec, stat_spec,
             *v_specs,
         ],
+        # m/l carry a trailing unit axis: a block's last two dims must
+        # tile (8, 128) or span the array, which (1, qh) would not
         out_specs=[
             pl.BlockSpec((1, 1, qh, d), lambda i, j, k, t, f: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, qh), lambda i, j, k, t, f: (i, j, 0)),
-            pl.BlockSpec((1, 1, qh), lambda i, j, k, t, f: (i, j, 0)),
+            pl.BlockSpec((1, 1, qh, 1), lambda i, j, k, t, f: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, qh, 1), lambda i, j, k, t, f: (i, j, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((qh, 1), jnp.float32),
@@ -178,9 +180,9 @@ def polar_paged_decode_grouped(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((s, hkv, qh, d), jnp.float32),
-            jax.ShapeDtypeStruct((s, hkv, qh), jnp.float32),
-            jax.ShapeDtypeStruct((s, hkv, qh), jnp.float32),
+            jax.ShapeDtypeStruct((s, hkv, qh, 1), jnp.float32),
+            jax.ShapeDtypeStruct((s, hkv, qh, 1), jnp.float32),
         ],
         interpret=interpret,
     )(page_table, flushed, q, codes, rs, rz, ts, tz, *v_in)
-    return out, m, l
+    return out, m[..., 0], l[..., 0]

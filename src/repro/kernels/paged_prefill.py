@@ -10,24 +10,33 @@ This kernel is the prefill twin of ``kernels/paged_decode.py``: one
 quantized prefix pages plus the chunk's own fp keys, with nothing dense
 ever materialized:
 
-    per kv head j, for each prefix page k of the slot's table row:
+    per kv head j, row block r of the folded queries, prefix page k:
         codes/stats/values <- pool[row[k]]            (index-map walk)
-        scores = LUT(q_fold, codes_k)                 (VPU select-tree)
+        K~ = rho~ (cos, sin)(theta~ - pi)             (page dequant, VPU)
+        scores = q_fold[r] @ K~^T                     (MXU)
         m, l, acc online-softmax update               (VMEM carry)
     final grid step (k == N):
-        s = q_fold @ k_chunk^T, causal-masked         (MXU, fp)
+        s = q_fold[r] @ k_chunk^T, causal-masked      (MXU, fp)
         same m/l/acc update, then out = acc / l       (flash finish)
 
 The chunk's ``Tc`` query rows ride folded onto the query-head axis
-(``QT = (Hq/Hkv) * Tc`` rows per kv head, row = qh * Tc + t), so the LUT
-select-tree and the MXU matmuls see one tall 2-D operand — the same
-folding ``chunk_prefill_attention`` uses. Causality needs no masking on
-the prefix steps (every chunk token sits at position ``start + t`` ≥
-``start`` > any prefix position); within the chunk the final step applies
-the standard triangular mask by ``t = row % Tc``. Because the fp causal
-tile shares the *same* online-softmax carry as the LUT prefix steps
-(``flash_prefill.py``'s m/l/acc structure), the kernel emits the complete
-normalized chunk output in one pass — no partial merge on the host.
+(``QT = (Hq/Hkv) * Tc`` rows per kv head, row = qh * Tc + t) — the same
+folding ``chunk_prefill_attention`` uses — and a grid axis tiles those
+rows into blocks of at most ``block_rows``, so VMEM use is bounded by the
+block and not by the chunk. Decode scores one query row per head against
+a page, where the angle LUT (``polar_attention._lut_scores_block``)
+beats dequantizing; a chunk scores hundreds of rows against the same
+page, so here each page is dequantized once into fp keys and scored on
+the MXU. The two forms are the same sum,
+``sum_j rho_j (q_x[j] cos_j + q_y[j] sin_j)``, in a different order.
+
+Causality needs no masking on the prefix steps (every chunk token sits
+at position ``start + t`` ≥ ``start`` > any prefix position); within the
+chunk the final step applies the standard triangular mask by
+``t = row % Tc``. Because the fp causal tile shares the *same*
+online-softmax carry as the prefix steps (``flash_prefill.py``'s m/l/acc
+structure), the kernel emits the complete normalized chunk output in one
+pass — no partial merge on the host.
 
 Dead grid steps (pages at or past ``start // g``) clamp their index maps
 to the last live prefix page, exactly as in ``paged_decode``: repeated
@@ -49,19 +58,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.polar_attention import _lut_scores_block
+from repro.kernels.polar_attention import to_f32
 
 Array = jax.Array
 NEG_INF = -1e30
 
 
+def _dequant_page_keys(codes, rs, rz, ts, tz, t_bits: int):
+    """One page's polar codes (g, P) -> fp32 key halves (k_x, k_y), each
+    (g, P): ``rho~ * (cos, sin)(theta~ - pi)`` per channel pair."""
+    codes = codes.astype(jnp.int32)     # the TPU compiler cannot shift uint8
+    rho = ((codes >> t_bits).astype(jnp.float32) + 0.5) * rs + rz
+    theta = ((codes & ((1 << t_bits) - 1)).astype(jnp.float32) + 0.5) * ts + tz
+    return rho * jnp.cos(theta - jnp.pi), rho * jnp.sin(theta - jnp.pi)
+
+
+def _dot_t(a, b):
+    """a (M, K) @ b (N, K)^T -> (M, N) in fp32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _paged_prefill_kernel(row_ref, info_ref, q_ref, kc_ref, vc_ref,
                           codes_ref, rs_ref, rz_ref, ts_ref, tz_ref,
                           v_ref, vs_ref, vz_ref, out_ref,
-                          m_ref, l_ref, acc_ref, *, r_bits: int, t_bits: int,
+                          m_ref, l_ref, acc_ref, *, t_bits: int,
                           quantized_values: bool, page_size: int,
-                          chunk_tokens: int, n_pages: int):
-    k = pl.program_id(1)
+                          chunk_tokens: int, n_pages: int, block_rows: int):
+    r, k = pl.program_id(1), pl.program_id(2)
     g = page_size
     start = info_ref[0]
     clen = info_ref[1]
@@ -72,15 +96,16 @@ def _paged_prefill_kernel(row_ref, info_ref, q_ref, kc_ref, vc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                       # (QT, d), scaled
+    q = q_ref[0].astype(jnp.float32)                       # (R, d), scaled
+    p_half = q.shape[1] // 2
 
     def _online(scores, mask, v):
         # one flash update of the shared m/l/acc carry.
-        # scores/mask: (QT, L); v: (L, d) with dead rows already zeroed.
-        m_prev = m_ref[...]                                # (QT, 1)
+        # scores/mask: (R, L); v: (L, d) with dead rows already zeroed.
+        m_prev = m_ref[...]                                # (R, 1)
         m_new = jnp.maximum(m_prev,
                             jnp.max(scores, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)  # (QT, L)
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)  # (R, L)
         corr = jnp.exp(m_prev - m_new)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
@@ -89,20 +114,18 @@ def _paged_prefill_kernel(row_ref, info_ref, q_ref, kc_ref, vc_ref,
 
     @pl.when(k < n_pages)
     def _prefix_page():
-        codes = codes_ref[0, 0][None]                      # (1, g, P)
-        scores = _lut_scores_block(
-            q, codes,
+        kx, ky = _dequant_page_keys(
+            codes_ref[0, 0],
             rs_ref[0, 0].astype(jnp.float32),
             rz_ref[0, 0].astype(jnp.float32),
             ts_ref[0, 0].astype(jnp.float32),
-            tz_ref[0, 0].astype(jnp.float32),
-            r_bits, t_bits)                                # (QT, g)
+            tz_ref[0, 0].astype(jnp.float32), t_bits)     # (g, P) each
+        scores = _dot_t(q[:, :p_half], kx) + _dot_t(q[:, p_half:], ky)
         pos = k * g + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        mask = pos < start                                 # (QT, g)
+        mask = pos < start                                 # (R, g)
         scores = jnp.where(mask, scores, NEG_INF)
         if quantized_values:
-            v = (v_ref[0, 0].astype(jnp.float32)
-                 * vs_ref[0, 0].astype(jnp.float32)
+            v = (to_f32(v_ref[0, 0]) * vs_ref[0, 0].astype(jnp.float32)
                  + vz_ref[0, 0].astype(jnp.float32))       # (g, d)
         else:
             v = v_ref[0, 0].astype(jnp.float32)
@@ -113,8 +136,9 @@ def _paged_prefill_kernel(row_ref, info_ref, q_ref, kc_ref, vc_ref,
     @pl.when(k == n_pages)
     def _chunk_tile():
         kc = kc_ref[0].astype(jnp.float32)                 # (Tc, d)
-        s = jnp.dot(q, kc.T, preferred_element_type=jnp.float32)  # (QT, Tc)
-        t_q = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % chunk_tokens
+        s = _dot_t(q, kc)                                  # (R, Tc)
+        row = r * block_rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        t_q = row % chunk_tokens
         t_k = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = (t_q >= t_k) & (t_k < clen)
         s = jnp.where(mask, s, NEG_INF)
@@ -122,22 +146,34 @@ def _paged_prefill_kernel(row_ref, info_ref, q_ref, kc_ref, vc_ref,
         vrow = jax.lax.broadcasted_iota(jnp.int32, (chunk_tokens, 1), 0)
         vc = jnp.where(vrow < clen, vc, 0.0)
         _online(s, mask, vc)
+        # the flash finish: every query row has at least its own diagonal
+        # lane unmasked once the chunk tile lands, so l > 0 for real rows;
+        # padded rows (t >= clen) stay fully masked and the l == 0 guard
+        # keeps them finite. The output block stays resident across k and
+        # is written back once, after this last step.
+        l = l_ref[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        out_ref[0] = acc_ref[...] / l_safe
 
-    # the flash finish: every query row has at least its own diagonal lane
-    # unmasked once the chunk tile lands, so l > 0 for real rows; padded
-    # rows (t >= clen) stay fully masked and the l == 0 guard keeps them
-    # finite. Written every step, last (chunk) step wins.
-    l = l_ref[...]
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    out_ref[0] = acc_ref[...] / l_safe
+
+def _row_block(qt: int, block_rows: int) -> int:
+    """Rows per grid block: the largest divisor of ``qt`` within
+    ``block_rows`` that is a multiple of 8 (the fp32 sublane tile), or all
+    of ``qt`` when it has no such divisor."""
+    for rb in range(min(block_rows, qt), 7, -1):
+        if qt % rb == 0 and rb % 8 == 0:
+            return rb
+    return qt
 
 
-@functools.partial(jax.jit, static_argnames=("r_bits", "t_bits", "interpret"))
+@functools.partial(jax.jit, static_argnames=("r_bits", "t_bits",
+                                             "block_rows", "interpret"))
 def polar_paged_prefill_grouped(
     q: Array, k_chunk: Array, v_chunk: Array, codes: Array, rs: Array,
     rz: Array, ts: Array, tz: Array, values, vscale, vzero,
     page_row: Array, start: Array, chunk_len: Array, *,
-    r_bits: int = 4, t_bits: int = 4, interpret: bool = True,
+    r_bits: int = 4, t_bits: int = 4, block_rows: int = 512,
+    interpret: bool,
 ):
     """One prefill chunk's fused attention, straight off the pools.
 
@@ -149,7 +185,8 @@ def polar_paged_prefill_grouped(
     vscale/vzero (PP, Hkv, g, 1) (pass vscale=None for fp values).
     page_row: (N,) int32 — the slot's table row (may be width-sliced to
     the live pages); start: () int32 page-aligned chunk offset;
-    chunk_len: () int32 real tokens in the chunk.
+    chunk_len: () int32 real tokens in the chunk. ``block_rows`` bounds
+    the folded query rows one grid step holds (see :func:`_row_block`).
 
     Returns (Hkv, QT, d) fp32 — the complete normalized chunk output.
     """
@@ -157,24 +194,28 @@ def polar_paged_prefill_grouped(
     _, _, g, p = codes.shape
     tc = k_chunk.shape[1]
     n = page_row.shape[0]
+    rb = _row_block(qt, block_rows)
     quantized_values = vscale is not None
     page_row = page_row.astype(jnp.int32)
     info = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(chunk_len, jnp.int32)])
 
-    def page_map(j, k, row_ref, info_ref):
+    def page_map(j, r, k, row_ref, info_ref):
         # clamp dead grid steps (k >= start // g, incl. the chunk step) to
         # the last live prefix page: repeated block indices skip the DMA
         live = jnp.maximum(info_ref[0] // g, 1)
         return (row_ref[jnp.minimum(k, live - 1)], j, 0, 0)
 
-    def head_map(j, k, row_ref, info_ref):
+    def head_map(j, r, k, row_ref, info_ref):
         return (j, 0, 0)
 
+    def rows_map(j, r, k, row_ref, info_ref):
+        return (j, r, 0)
+
     kern = functools.partial(
-        _paged_prefill_kernel, r_bits=r_bits, t_bits=t_bits,
+        _paged_prefill_kernel, t_bits=t_bits,
         quantized_values=quantized_values, page_size=g, chunk_tokens=tc,
-        n_pages=n)
+        n_pages=n, block_rows=rb)
 
     codes_spec = pl.BlockSpec((1, 1, g, p), page_map)
     stat_spec = pl.BlockSpec((1, 1, 1, p), page_map)
@@ -186,27 +227,27 @@ def polar_paged_prefill_grouped(
     else:
         dummy = jnp.zeros((1, 1, 1, 1), jnp.float32)
         v_in = (values, dummy, dummy)
-        zmap = lambda j, k, r, i: (0, 0, 0, 0)
+        zmap = lambda j, r, k, row_ref, info_ref: (0, 0, 0, 0)
         v_specs = [pl.BlockSpec((1, 1, g, d), page_map),
                    pl.BlockSpec((1, 1, 1, 1), zmap),
                    pl.BlockSpec((1, 1, 1, 1), zmap)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hkv, n + 1),
+        grid=(hkv, qt // rb, n + 1),
         in_specs=[
-            pl.BlockSpec((1, qt, d), head_map),
+            pl.BlockSpec((1, rb, d), rows_map),
             pl.BlockSpec((1, tc, d), head_map),
             pl.BlockSpec((1, tc, d), head_map),
             codes_spec,
             stat_spec, stat_spec, stat_spec, stat_spec,
             *v_specs,
         ],
-        out_specs=pl.BlockSpec((1, qt, d), head_map),
+        out_specs=pl.BlockSpec((1, rb, d), rows_map),
         scratch_shapes=[
-            pltpu.VMEM((qt, 1), jnp.float32),
-            pltpu.VMEM((qt, 1), jnp.float32),
-            pltpu.VMEM((qt, d), jnp.float32),
+            pltpu.VMEM((rb, 1), jnp.float32),
+            pltpu.VMEM((rb, 1), jnp.float32),
+            pltpu.VMEM((rb, d), jnp.float32),
         ],
     )
     return pl.pallas_call(

@@ -29,13 +29,23 @@ Array = jax.Array
 NEG_INF = -1e30
 
 
+def to_f32(x):
+    """Widen a loaded tile to fp32. The TPU compiler has no direct cast from
+    unsigned bytes to floats, so uint8 codes go through int32 first."""
+    if x.dtype == jnp.uint8:
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
 def _lut_scores_block(q, codes, rs, rz, ts, tz, r_bits, t_bits):
     """Shared LUT score tile: q (Qh,d) fp32; codes (gb,g,P) -> (Qh, gb*g)."""
     qh, d = q.shape
     p = d // 2
     qx, qy = q[:, :p], q[:, p:]
     gb, g, _ = codes.shape
-    tc = (codes & ((1 << t_bits) - 1)).astype(jnp.int32)
+    # shifts and masks run on int32: the TPU compiler cannot shift uint8
+    codes = codes.astype(jnp.int32)
+    tc = codes & ((1 << t_bits) - 1)
     rc = (codes >> t_bits).astype(jnp.float32)
     rho = (rc + 0.5) * rs[:, None, :] + rz[:, None, :]
     gathered = jnp.zeros((qh, gb, g, p), jnp.float32)
@@ -82,7 +92,7 @@ def _attn_kernel(q_ref, codes_ref, rs_ref, rz_ref, ts_ref, tz_ref, v_ref,
     corr = jnp.exp(m_prev - m_new)
 
     if quantized_values:
-        v = (v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
+        v = (to_f32(v_ref[0, 0]) * vs_ref[0, 0].astype(jnp.float32)
              + vz_ref[0, 0].astype(jnp.float32))       # (S, d)
     else:
         v = v_ref[0, 0].astype(jnp.float32)
@@ -95,8 +105,8 @@ def _attn_kernel(q_ref, codes_ref, rs_ref, rz_ref, ts_ref, tz_ref, v_ref,
     # Final carry lands in the (b, h)-indexed output tiles on the last step;
     # intermediate writes are overwritten (n is the innermost grid dim).
     out_ref[0, 0] = acc_ref[...]
-    m_out_ref[0, 0] = m_ref[..., 0]
-    l_out_ref[0, 0] = l_ref[..., 0]
+    m_out_ref[0, 0] = m_ref[...]
+    l_out_ref[0, 0] = l_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -104,7 +114,7 @@ def _attn_kernel(q_ref, codes_ref, rs_ref, rz_ref, ts_ref, tz_ref, v_ref,
 def polar_decode_attention_grouped(
     q: Array, codes: Array, rs: Array, rz: Array, ts: Array, tz: Array,
     values, vscale, vzero, length: Array, *, r_bits: int = 4,
-    t_bits: int = 4, block_groups: int = 4, interpret: bool = True,
+    t_bits: int = 4, block_groups: int = 4, interpret: bool,
 ):
     """Fused flash-decode over the grouped cache segment.
 
@@ -158,15 +168,17 @@ def polar_decode_attention_grouped(
             *v_specs,
             pl.BlockSpec((1, 1), lambda i, j, n: (i, 0)),
         ],
+        # m/l carry a trailing unit axis: a block's last two dims must
+        # tile (8, 128) or span the array, which (1, qh) would not
         out_specs=[
             pl.BlockSpec((1, 1, qh, d), lambda i, j, n: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, qh), lambda i, j, n: (i, j, 0)),
-            pl.BlockSpec((1, 1, qh), lambda i, j, n: (i, j, 0)),
+            pl.BlockSpec((1, 1, qh, 1), lambda i, j, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, qh, 1), lambda i, j, n: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, qh, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, qh), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, qh), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, qh, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, qh, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((qh, 1), jnp.float32),
@@ -175,4 +187,4 @@ def polar_decode_attention_grouped(
         ],
         interpret=interpret,
     )(q, codes, rs, rz, ts, tz, *v_in, len2)
-    return out, m, l
+    return out, m[..., 0], l[..., 0]

@@ -50,7 +50,7 @@ def _encode_kernel(k_ref, codes_ref, rs_ref, rz_ref, ts_ref, tz_ref, *,
                                              "scale_dtype", "interpret"))
 def polar_encode(k: Array, *, r_bits: int = 4, t_bits: int = 4,
                  group_size: int = 128, scale_dtype: str = "float32",
-                 interpret: bool = True):
+                 interpret: bool):
     """Quantize keys (B, Hkv, T, d) with T % group_size == 0.
 
     Returns (codes (B,Hkv,G,g,P) uint8, rho_scale, rho_zero, theta_scale,

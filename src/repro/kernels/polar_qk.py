@@ -67,7 +67,7 @@ def _qk_kernel(q_ref, codes_ref, rs_ref, rz_ref, ts_ref, tz_ref, out_ref, *,
                                              "block_groups", "interpret"))
 def polar_qk_scores(q: Array, codes: Array, rs: Array, rz: Array, ts: Array,
                     tz: Array, *, r_bits: int = 4, t_bits: int = 4,
-                    block_groups: int = 4, interpret: bool = True) -> Array:
+                    block_groups: int = 4, interpret: bool) -> Array:
     """LUT q.K scores. Shapes as in ref.ref_polar_qk_scores.
 
     q: (B, Hkv, Qh, d); codes: (B, Hkv, G, g, P); stats: (B, Hkv, G, 1, P).

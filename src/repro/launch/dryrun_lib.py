@@ -29,13 +29,12 @@ from repro.configs import SHAPES, get_config
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed import ctx
 from repro.distributed import sharding as shd
+from repro.launch.chips import DRYRUN_TARGET, chip_peaks
 from repro.models.registry import Model, get_model
 from repro.train.train_step import StepConfig, lower_train_step
 
-# v5e hardware model (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 4.5e10 * 1.0        # ~50 GB/s per link (3D torus, per-direction)
+# the chip the dry-run's roofline terms are computed against
+TARGET = chip_peaks(DRYRUN_TARGET)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -227,8 +226,6 @@ def run_cell(arch: str, shape_name: str, mesh: Mesh, out_dir: str,
     # XLA's cost_analysis counts while (scan) bodies ONCE — kept only for
     # reference. The loop-aware static model (hlo_cost) is authoritative.
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # pre-0.5 jax returns [dict]
-        cost = cost[0] if cost else {}
     rec["cost_xla_raw"] = {k: float(v) for k, v in cost.items()
                            if isinstance(v, (int, float)) and
                            k in ("flops", "bytes accessed", "transcendentals")}
@@ -257,9 +254,9 @@ def roofline_terms(rec: dict, n_devices: int) -> dict:
     bytes_acc = rec.get("cost", {}).get("bytes accessed", 0.0)
     coll = rec.get("collectives", {}).get("total_bytes", 0.0)
     return {
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": bytes_acc / HBM_BW,
-        "collective_s": coll / ICI_BW,
+        "compute_s": flops / TARGET.bf16_flops,
+        "memory_s": bytes_acc / TARGET.hbm_bw,
+        "collective_s": coll / TARGET.ici_bw,
     }
 
 

@@ -17,18 +17,12 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis_types where this jax supports them.
-
-    Older jax (<0.5) has neither ``jax.sharding.AxisType`` nor the
-    ``axis_types`` kwarg; Auto is its only behavior, so plain make_mesh is
-    equivalent there.
-    """
-    try:
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axis types, over ``devices`` (default:
+    all of them)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -45,6 +45,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, reduce_for_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serve import (
     ContinuousBatchingEngine, GenerationConfig, Request, ServeEngine,
@@ -69,12 +70,13 @@ def main(argv=None) -> int:
     ap.add_argument("--int8-layers", type=int, default=0,
                     help="mixed policy: run the first N layers at int8 "
                          "(KVTuner-style) and the rest at --quant")
-    ap.add_argument("--decode-backend", default="jnp",
+    ap.add_argument("--decode-backend", default="paged_fused",
                     choices=["jnp", "gathered", "paged_fused", "ref",
                              "interpret", "pallas"],
                     help="decode-attention backend (paged_fused = "
-                         "page-native fused kernel on the paged path)")
-    ap.add_argument("--prefill-backend", default="jnp",
+                         "page-native fused kernel on the paged path: the "
+                         "Pallas kernel on TPU, its jnp oracle elsewhere)")
+    ap.add_argument("--prefill-backend", default="paged_fused",
                     choices=["jnp", "paged_fused", "ref", "interpret",
                              "pallas"],
                     help="chunked-prefill attention backend (cb engine "
@@ -138,6 +140,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    devs = jax.devices()
+    print(f"[serve] platform={devs[0].platform} "
+          f"device_kind={devs[0].device_kind} devices={len(devs)}")
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

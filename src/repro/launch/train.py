@@ -14,6 +14,7 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_config, reduce_for_smoke
 from repro.data import SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.train import Trainer, TrainerConfig
 from repro.train.train_step import StepConfig
@@ -33,6 +34,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

@@ -146,16 +146,36 @@ def test_codec_scores_match_dequant_matmul(name):
 # Golden parity with the pre-registry implementation
 # ---------------------------------------------------------------------------
 
-# Captured from the seed (string-dispatch) implementation at commit
-# "PR 1" shapes B,H,d,g,T = 1,2,32,16,70: (key-code sum, sum(out), sum|out|)
-# for prefill(64) + 6 appends + decode_attention, fp32, PRNGKey(42)/(7).
+def _golden_inputs(seed: int, shape) -> jnp.ndarray:
+    """Platform-exact pseudo-normal fp32 inputs that depend on no PRNG
+    implementation: a 64-bit LCG (Knuth's MMIX constants) drawn in pure
+    integer arithmetic, four 16-bit draws summed per value (Irwin-Hall)
+    and scaled to about unit variance. Every value is an exact multiple of
+    2**-15, so the fp32 inputs are bit-identical everywhere."""
+    n = int(np.prod(shape)) * 4
+    x = np.empty(n, np.uint64)
+    state = np.uint64(seed)
+    a, c = np.uint64(6364136223846793005), np.uint64(1442695040888963407)
+    with np.errstate(over="ignore"):
+        for i in range(n):
+            state = state * a + c
+            x[i] = state >> np.uint64(48)
+    draws = x.reshape(-1, 4).astype(np.int64).sum(axis=1) - 2 * 65535
+    return jnp.asarray((draws * 2.0 ** -15).reshape(shape), jnp.float32)
+
+
+# Pinned from the codec registry at shapes B,H,d,g,T = 1,2,32,16,70:
+# (key-code sum, sum(out), sum|out|) for prefill(64) + 6 appends +
+# decode_attention, fp32, on the _golden_inputs streams below. The
+# registry was itself pinned to the pre-registry string-dispatch
+# implementation; these sums guard both against behaviour drift.
 _GOLDEN = {
-    "polar": (227428, 5.3508195877e+00, 1.8290977478e+01),
-    "kivi": (30781, 4.9970455170e+00, 1.9550201416e+01),
-    "zipcache": (31099, 4.9251194000e+00, 1.8520610809e+01),
-    "int": (33721, 5.0163354874e+00, 1.9066673279e+01),
-    "none": (0, 4.9392638206e+00, 1.8867635727e+01),
-    "polar+v4": (227428, 5.5629472733e+00, 1.8356626511e+01),
+    "polar": (236475, 1.8684691191e+00, 2.7099872589e+01),
+    "kivi": (30876, 1.3096932173e+00, 2.7681785583e+01),
+    "zipcache": (30756, 1.3180801868e+00, 2.6527612686e+01),
+    "int": (33640, 1.2014031410e+00, 2.6737098694e+01),
+    "none": (0, 1.3729696274e+00, 2.7152233124e+01),
+    "polar+v4": (236475, 1.4071828127e+00, 2.6993419647e+01),
 }
 
 
@@ -164,16 +184,15 @@ def test_golden_parity_with_pre_registry_implementation(name):
     method, _, v = name.partition("+v")
     value_bits = int(v) if v else 0
     B, H, d, g, T = 1, 2, 32, 16, 70
-    k1, k2 = jax.random.split(jax.random.PRNGKey(42))
-    k = jax.random.normal(k1, (B, H, T, d))
-    v_ = jax.random.normal(k2, (B, H, T, d))
+    k = _golden_inputs(42, (B, H, T, d))
+    v_ = _golden_inputs(43, (B, H, T, d))
     cfg = QuantConfig(method=method, group_size=g, key_bits=4,
                       value_bits=value_bits, residual_dtype="float32")
     cache = prefill(init_cache(cfg, B, H, d, 128, dtype=jnp.float32),
                     k[:, :, :64], v_[:, :, :64])
     for i in range(64, T):
         cache = append(cache, k[:, :, i : i + 1], v_[:, :, i : i + 1])
-    q = jax.random.normal(jax.random.PRNGKey(7), (B, H * 2, d))
+    q = _golden_inputs(7, (B, H * 2, d))
     out = decode_attention(cache, q)
     code_sum, out_sum, out_abs = _GOLDEN[name]
     if cache.key_codes.dtype == jnp.uint8:

@@ -36,6 +36,29 @@ def test_collective_census_parses_kinds():
     assert c["total_bytes"] > 0
 
 
+def test_chip_peaks_known_kind_and_unknown_kind_raises():
+    from repro.launch.chips import DRYRUN_TARGET, chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert chip_peaks(DRYRUN_TARGET) is v5e
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+
+
+def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch):
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    root = Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == root / ".jax_cache"
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

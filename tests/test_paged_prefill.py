@@ -110,6 +110,30 @@ def test_polar_pallas_kernel_parity_interpret(value_bits):
                                atol=2e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("value_bits", [0, 4])
+def test_pallas_prefill_row_blocks_match_one_block(value_bits):
+    """The kernel tiles the folded query rows over a grid axis. Blocks of
+    8 rows (half of one query head's TC rows, so the causal mask must use
+    global row indices) reproduce the single-block result."""
+    from repro.kernels.paged_prefill import polar_paged_prefill_grouped
+    cfg = _cfg("polar", value_bits=value_bits)
+    cache, row, start = _prefix_cache(cfg)
+    q, kc, vc = _chunk()
+    qf = (q * d ** -0.5).reshape(H, QPK * TC, d)
+    sc = cache.key_scales
+    if value_bits:
+        vals = (cache.value_codes, cache.value_scale, cache.value_zero)
+    else:
+        vals = (cache.value_fp, None, None)
+    outs = [polar_paged_prefill_grouped(
+        qf, kc[0], vc[0], cache.key_codes, sc["rho_scale"], sc["rho_zero"],
+        sc["theta_scale"], sc["theta_zero"], *vals, row, start,
+        jnp.asarray(13, jnp.int32), block_rows=br, interpret=True)
+        for br in (8, QPK * TC)]
+    np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[1]),
+                               atol=1e-6, rtol=1e-6)
+
+
 def test_width_sliced_row_matches_full_row():
     """The engine buckets the table row to the pages covering the live
     prefix; masked lanes contribute exactly-0.0 probability, so slicing is
