@@ -457,18 +457,14 @@ def run_runahead_sweep(cfg, params, args) -> dict:
                 "outputs_digest": digest,
                 "outputs_bit_identical": same,
             }
+            arm["host_sync_s"] = r["host_sync_s"]
             if "runahead" in r:
                 arm["horizons"] = r["runahead"]["horizons"]
                 arm["horizon_tokens"] = r["runahead"]["tokens"]
-                arm["dispatch_gap_ewma_s"] = \
-                    r["runahead"]["dispatch_gap_ewma_s"]
-                arm["sync_wait_s"] = r["runahead"]["sync_wait_s"]
             out["arms"].append(arm)
-            extra = ""
+            extra = f" host-sync={arm['host_sync_s'] * 1e3:7.1f}ms"
             if "runahead" in r:
-                extra = (f" horizons={arm['horizons']:4d} "
-                         f"gap-ewma={arm['dispatch_gap_ewma_s'] * 1e3:6.2f}ms"
-                         f" sync-wait={arm['sync_wait_s'] * 1e3:7.1f}ms")
+                extra = f" horizons={arm['horizons']:4d}" + extra
             print(f"runahead slots={slots:2d} h={h}: "
                   f"tok/s={r['tokens_per_s']:8.1f} "
                   f"({arm['speedup_vs_h1']:.2f}x) "

@@ -190,19 +190,19 @@ def render(bench: dict) -> str:
             f"H=1 per-token dispatch baseline: {ident}.",
             "",
             "| slots | H | tokens/s | speedup vs H=1 | horizons | "
-            "dispatch gap EWMA (ms) | outputs |",
+            "host sync (ms) | outputs |",
             "|---|---|---|---|---|---|---|",
         ]
         for arm in ra["arms"]:
             ident = ("bit-identical" if arm["outputs_bit_identical"]
                      else "DIVERGED")
             horizons = arm.get("horizons", "—")
-            gap = (_fmt_ms(arm["dispatch_gap_ewma_s"])
-                   if "dispatch_gap_ewma_s" in arm else "—")
+            sync = (_fmt_ms(arm["host_sync_s"])
+                    if "host_sync_s" in arm else "—")
             lines.append(
                 f"| {arm['slots']} | {arm['h']} | "
                 f"{arm['tokens_per_s']:.1f} | "
-                f"{arm['speedup_vs_h1']:.2f}x | {horizons} | {gap} | "
+                f"{arm['speedup_vs_h1']:.2f}x | {horizons} | {sync} | "
                 f"{ident} |")
         lines += [
             "",

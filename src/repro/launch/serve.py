@@ -280,6 +280,9 @@ def main(argv=None) -> int:
               f"p50 {out['p50_latency_s'] * 1e3:.1f}ms  "
               f"cache {out['cache_bytes'] / 2**20:.2f} MiB  "
               f"prefill-chunk {out['prefill_chunk']}")
+        print(f"[serve] {out['decode_steps']} decode steps  "
+              f"{out['prefill_chunks']} prefill chunks  "
+              f"host sync {out['host_sync_s'] * 1e3:.1f}ms")
         if "qos" in out:
             print(f"[serve] qos: {out['n_shed']} shed  "
                   f"{out['n_rejected']} rejected  "
@@ -295,9 +298,7 @@ def main(argv=None) -> int:
             ra = out["runahead"]
             print(f"[serve] runahead h={ra['h']}  "
                   f"{ra['horizons']} horizons  "
-                  f"{ra['tokens']} horizon tokens  "
-                  f"dispatch-gap ewma "
-                  f"{ra['dispatch_gap_ewma_s'] * 1e3:.2f}ms")
+                  f"{ra['tokens']} horizon tokens")
         if args.prefix_cache:
             print(f"[serve] prefix hit rate "
                   f"{out['prefix_hit_rate'] * 100:.1f}%  "

@@ -120,23 +120,37 @@ def get_model(cfg: ModelConfig) -> Model:
         # vlm prefill needs the patch frontend; the paged attention path
         # has no sliding-window masking, so windowed configs are excluded
         if cfg.family != "vlm" and cfg.window == 0:
+            # the serving programs are named functions: jax.jit calls
+            # each compiled program jit_<name>, the name a device trace
+            # shows it by
+            def prefill(p, toks, s, slot, row, tl):
+                return TF.prefill_paged_fn(p, toks, cfg, s, slot, row, tl)
+
+            def prefill_chunk(p, toks, s, slot, row, start, cl):
+                return TF.prefill_paged_chunk_fn(p, toks, cfg, s, slot, row,
+                                                 start, cl)
+
+            def decode_step(p, s, t, table, active):
+                return TF.decode_paged_fn(p, s, t, table, active, cfg)
+
+            def runahead_horizon(p, s, t, table, active, key, rem, done,
+                                 horizon, temperature, top_k, eos_id):
+                return TF.decode_runahead_fn(
+                    p, s, t, table, active, key, rem, done, cfg,
+                    horizon=horizon, temperature=temperature, top_k=top_k,
+                    eos_id=eos_id)
+
+            def copy_pages(caches, src, dst):
+                return TF.copy_state_pages(caches, src, dst)
+
             paged = dict(
                 init_paged_state=lambda layout: TF.init_paged_caches(
                     cfg, layout),
-                prefill_paged=lambda p, toks, s, slot, row, tl:
-                    TF.prefill_paged_fn(p, toks, cfg, s, slot, row, tl),
-                prefill_paged_chunk=lambda p, toks, s, slot, row, start, cl:
-                    TF.prefill_paged_chunk_fn(p, toks, cfg, s, slot, row,
-                                              start, cl),
-                decode_paged=lambda p, s, t, table, active:
-                    TF.decode_paged_fn(p, s, t, table, active, cfg),
-                decode_runahead=lambda p, s, t, table, active, key, rem,
-                    done, horizon, temperature, top_k, eos_id:
-                    TF.decode_runahead_fn(p, s, t, table, active, key,
-                                          rem, done, cfg, horizon=horizon,
-                                          temperature=temperature,
-                                          top_k=top_k, eos_id=eos_id),
-                copy_pages=TF.copy_state_pages,
+                prefill_paged=prefill,
+                prefill_paged_chunk=prefill_chunk,
+                decode_paged=decode_step,
+                decode_runahead=runahead_horizon,
+                copy_pages=copy_pages,
                 decode_paged_collect=lambda p, s, t, table, active:
                     TF.decode_paged_collect_fn(p, s, t, table, active, cfg),
                 commit_paged=lambda s, kv, table, keep:

@@ -31,6 +31,7 @@ budget reset once per cycle (admit phase), not once per step.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Iterator, Optional
@@ -71,6 +72,13 @@ def _sample(logits, key, gen: GenerationConfig):
         vals, _ = jax.lax.top_k(logits, gen.top_k)
         logits = jnp.where(logits < vals[..., -1:], -1e30, logits)
     return jax.random.categorical(key, logits).astype(jnp.int32)
+
+
+#: A host span on the profiler's clock: ``engine.*`` spans nest inside
+#: ``engine.step`` and lie in one trace with the device's programs, so a
+#: device idle gap can be put down to the engine code the host was in.
+#: Off the profiler one costs about a microsecond.
+_span = jax.profiler.TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -152,7 +160,6 @@ class ServeEngine:
     def _ctx(self):
         if self.mesh is not None and self.rules is not None:
             return ctx.use_sharding(self.mesh, self.rules)
-        import contextlib
         return contextlib.nullcontext()
 
     def generate(self, batch: dict,
@@ -298,6 +305,9 @@ class EngineCore:
                     "path (prefill_paged_chunk)")
         self.prefill_chunk = chunk
         self.prefill_budget = int(prefill_budget) if prefill_budget else chunk
+        # the registry names each function after its program (jit_prefill,
+        # jit_prefill_chunk, jit_copy_pages, jit_decode_step,
+        # jit_runahead_horizon; the verifier's is jit_verify)
         self._prefill = jax.jit(model.prefill_paged)
         if chunk:
             self._prefill_chunk = jax.jit(model.prefill_paged_chunk,
@@ -406,22 +416,20 @@ class EngineCore:
         self._active_hist: list[int] = []
         self._step_times: list[float] = []
         self.decode_steps = 0
+        self.prefill_chunks = 0     # chunked-prefill dispatches
         self.prefill_computed = 0   # prefill tokens run through the model
         self.prefill_skipped = 0    # prefill tokens served from adoption
         self.cow_splits = 0
         self.spec_steps = 0         # decode steps that verified >=1 draft
         self.spec_drafted = 0       # draft tokens sent to verification
         self.spec_accepted = 0      # draft tokens accepted
+        self.host_sync_s = 0.0      # host time blocked in engine.sync
         # run-ahead pipeline state (DESIGN.md §18): the in-flight horizon
-        # record (which carries its own optimistic per-slot budgets) and
-        # the host-vs-device attribution metrics
+        # record (which carries its own optimistic per-slot budgets)
         self._inflight: Optional[dict] = None
         self._land_t = 0.0          # wall time the last horizon landed
         self.runahead_horizons = 0
         self.runahead_tokens = 0
-        self._gap_ewma = None       # host overlap per horizon (EWMA, s)
-        self._sync_wait_s = 0.0     # host time blocked on landing blocks
-        self._overlap_s = 0.0       # host time overlapped w/ device work
 
     # --- request intake ---------------------------------------------------
 
@@ -578,8 +586,16 @@ class EngineCore:
     def _ctx(self):
         if self.mesh is not None and self.rules is not None:
             return ctx.use_sharding(self.mesh, self.rules)
-        import contextlib
         return contextlib.nullcontext()
+
+    @contextlib.contextmanager
+    def _sync(self, what: str):
+        """An ``engine.sync`` span around one blocking fetch of the
+        serving path; its time counts to ``host_sync_s``."""
+        t0 = time.monotonic()
+        with _span("engine.sync", what=what):
+            yield
+        self.host_sync_s += time.monotonic() - t0
 
     def _place_state(self, state):
         """Place fresh paged state on the mesh: page pools partitioned
@@ -672,7 +688,7 @@ class EngineCore:
 
         Idle with scheduled arrivals jumps the clock; idle with no work
         at all returns ``[]`` immediately (streaming drivers poll)."""
-        with self._ctx():
+        with _span("engine.step", cycle=self._cycle), self._ctx():
             intake = self.take_intake_events()
             return intake + self._step() if intake else self._step()
 
@@ -818,6 +834,10 @@ class EngineCore:
         Chunked mode queues the prompt for interleaved chunk prefill;
         classic mode prefills the whole context in one shot (a preempted
         request resumes by prefilling its full context either way)."""
+        with _span("engine.admit", rid=req.rid):
+            return self._admit_slot(req)
+
+    def _admit_slot(self, req: Request) -> list[TokenEvent]:
         slot = self.sched.admit(req)
         req.state = PREFILLING
         self._admit_seq[slot] = self._n_admitted
@@ -836,19 +856,20 @@ class EngineCore:
             self._lengths[slot] = req.prefix_hit_tokens
             self.prefill_skipped += req.prefix_hit_tokens
             return events
-        toks = np.zeros((1, self._bucket(tl)), np.int32)
-        toks[0, :tl] = ctx_toks
-        t0 = time.monotonic()
-        logits, self.state = self._prefill(
-            self.params, jnp.asarray(toks), self.state,
-            jnp.asarray(slot, jnp.int32),
-            self.sched.alloc.table()[slot],
-            jnp.asarray(tl, jnp.int32))
-        self._key, sub = jax.random.split(self._key)
-        tok = self._sample(logits, sub, self.gen)
+        with _span("engine.dispatch", program="prefill"):
+            toks = np.zeros((1, self._bucket(tl)), np.int32)
+            toks[0, :tl] = ctx_toks
+            t0 = time.monotonic()
+            logits, self.state = self._prefill(
+                self.params, jnp.asarray(toks), self.state,
+                jnp.asarray(slot, jnp.int32),
+                self.sched.alloc.table()[slot],
+                jnp.asarray(tl, jnp.int32))
+        tok = self._sample_dispatch(logits)
         # one numpy fetch for the whole dispatch (np.asarray blocks until
         # the device is done), not a ready-barrier plus a scalar D2H
-        tok0 = int(np.asarray(tok)[0])  # sync: classic prefill first token
+        with self._sync("first_token"):
+            tok0 = int(np.asarray(tok)[0])  # sync: classic prefill first token
         dt = time.monotonic() - t0
         self.clock += dt
         if self._prefill_rate is not None:
@@ -865,45 +886,58 @@ class EngineCore:
         tl = len(ctx_toks)
         c = self.prefill_chunk
         clen = min(c, tl - off)
-        toks = np.zeros((1, c), np.int32)
-        toks[0, :clen] = ctx_toks[off:off + clen]
-        row = self.sched.alloc.table()[slot]
-        if self._prefill_slicing:
-            # width-slice the row to the pages this chunk touches: the
-            # page-native kernel then reads O(live prefix), not
-            # O(capacity) (one compile per pow2 bucket, as in decode)
-            row = row[:self._step_width(
-                cdiv(off + clen, self.layout.page_size))]
-        t0 = time.monotonic()
-        logits, self.state = self._prefill_chunk(
-            self.params, jnp.asarray(toks), self.state,
-            jnp.asarray(slot, jnp.int32), row,
-            jnp.asarray(off, jnp.int32),
-            jnp.asarray(clen, jnp.int32))
-        self._progressed = True
-        self._budget_left -= clen
-        self.prefill_computed += clen
-        cur["off"] = off + clen
-        self._lengths[slot] = off + clen
-        if cur["off"] >= tl:
-            # final chunk: its last-token logits seed decode
-            self._key, sub = jax.random.split(self._key)
-            tok = self._sample(logits, sub, self.gen)
-            # single numpy fetch per dispatch (see _admit)
-            tok0 = int(np.asarray(tok)[0])  # sync: final-chunk first token
+        with _span("engine.prefill_chunk", rid=self.sched.active[slot].rid,
+                   tokens=clen):
+            with _span("engine.dispatch", program="prefill_chunk"):
+                toks = np.zeros((1, c), np.int32)
+                toks[0, :clen] = ctx_toks[off:off + clen]
+                row = self.sched.alloc.table()[slot]
+                if self._prefill_slicing:
+                    # width-slice the row to the pages this chunk touches:
+                    # the page-native kernel then reads O(live prefix), not
+                    # O(capacity) (one compile per pow2 bucket, as in
+                    # decode)
+                    row = row[:self._step_width(
+                        cdiv(off + clen, self.layout.page_size))]
+                t0 = time.monotonic()
+                logits, self.state = self._prefill_chunk(
+                    self.params, jnp.asarray(toks), self.state,
+                    jnp.asarray(slot, jnp.int32), row,
+                    jnp.asarray(off, jnp.int32),
+                    jnp.asarray(clen, jnp.int32))
+            self._progressed = True
+            self._budget_left -= clen
+            self.prefill_computed += clen
+            self.prefill_chunks += 1
+            cur["off"] = off + clen
+            self._lengths[slot] = off + clen
+            if cur["off"] >= tl:
+                # final chunk: its last-token logits seed decode
+                tok = self._sample_dispatch(logits)
+                # single numpy fetch per dispatch (see _admit)
+                with self._sync("first_token"):
+                    tok0 = int(np.asarray(tok)[0])  # sync: final-chunk first token
+                dt = time.monotonic() - t0
+                self.clock += dt
+                if self._prefill_rate is not None:
+                    self._prefill_rate.observe(clen, dt)
+                del self._prefilling[slot]
+                self.sched.register_prefix(slot)
+                return self._take_first_token(slot, tok0, tl)
+            with self._sync("chunk_barrier"):
+                jax.block_until_ready(logits)  # sync: chunk completion barrier (honest clock)
             dt = time.monotonic() - t0
             self.clock += dt
             if self._prefill_rate is not None:
                 self._prefill_rate.observe(clen, dt)
-            del self._prefilling[slot]
-            self.sched.register_prefix(slot)
-            return self._take_first_token(slot, tok0, tl)
-        jax.block_until_ready(logits)  # sync: chunk completion barrier (honest clock)
-        dt = time.monotonic() - t0
-        self.clock += dt
-        if self._prefill_rate is not None:
-            self._prefill_rate.observe(clen, dt)
-        return []
+            return []
+
+    def _sample_dispatch(self, logits):
+        """Split the session key and sample one token per row of
+        ``logits`` on the device (no fetch)."""
+        with _span("engine.dispatch", program="sample"):
+            self._key, sub = jax.random.split(self._key)
+            return self._sample(logits, sub, self.gen)
 
     def _take_first_token(self, slot: int, tok0: int,
                           tl: int) -> list[TokenEvent]:
@@ -941,9 +975,13 @@ class EngineCore:
         """Batched decode over non-stalled, fully-prefilled slots; falls
         back to recompute-preemption when nothing can run and no chunk
         progressed this cycle."""
-        sched, g = self.sched, self.layout.page_size
-        if not sched.active:
+        if not self.sched.active:
             return []   # cancellation emptied the cycle mid-flight
+        with _span("engine.decode", active=len(self.sched.active)):
+            return self._decode_slots()
+
+    def _decode_slots(self) -> list[TokenEvent]:
+        sched, g = self.sched, self.layout.page_size
         drafts: dict[int, list[int]] = {}
         spans = None
         want_runahead = self._runahead_want()
@@ -1000,9 +1038,10 @@ class EngineCore:
                         ok = False
                         break
                     src, dst = sched.alloc.cow(sl, pidx)
-                    self.state = self._copy_pages(
-                        self.state, jnp.asarray(src, jnp.int32),
-                        jnp.asarray(dst, jnp.int32))
+                    with _span("engine.dispatch", program="copy_pages"):
+                        self.state = self._copy_pages(
+                            self.state, jnp.asarray(src, jnp.int32),
+                            jnp.asarray(dst, jnp.int32))
                     self.cow_splits += 1
                 if ok:
                     safe.append(sl)
@@ -1048,20 +1087,21 @@ class EngineCore:
         spec session when no slot has drafts this step)."""
         sched, g = self.sched, self.layout.page_size
         s = self.layout.slots
-        mask = np.zeros((s,), bool)
-        mask[step_slots] = True
-        # width-slice the page table to the live pages of this step's
-        # batch: the decode step then reads O(live tokens) instead of
-        # O(pool capacity) (one compile per pow2 bucket)
-        w = self._step_width(
-            max(int(self._lengths[sl]) // g + 1 for sl in step_slots))
-        t0 = time.monotonic()
-        logits, self.state = self._decode(
-            self.params, self.state, jnp.asarray(self._next_tok),
-            sched.alloc.table()[:, :w], jnp.asarray(mask))
-        self._key, sub = jax.random.split(self._key)
-        toks = np.asarray(
-            self._sample(logits, sub, self.gen))  # sync: H=1 decode token fetch
+        with _span("engine.dispatch", program="decode_step"):
+            mask = np.zeros((s,), bool)
+            mask[step_slots] = True
+            # width-slice the page table to the live pages of this step's
+            # batch: the decode step then reads O(live tokens) instead of
+            # O(pool capacity) (one compile per pow2 bucket)
+            w = self._step_width(
+                max(int(self._lengths[sl]) // g + 1 for sl in step_slots))
+            t0 = time.monotonic()
+            logits, self.state = self._decode(
+                self.params, self.state, jnp.asarray(self._next_tok),
+                sched.alloc.table()[:, :w], jnp.asarray(mask))
+        tok = self._sample_dispatch(logits)
+        with self._sync("decode_tokens"):
+            toks = np.asarray(tok)  # sync: H=1 decode token fetch
         step_s = time.monotonic() - t0
         self.clock += step_s
         self.decode_steps += 1
@@ -1126,17 +1166,18 @@ class EngineCore:
         the host does not block on the block — tokens reconcile when it
         lands (the next step, or a cancel arriving mid-flight)."""
         s = self.layout.slots
-        mask = np.zeros((s,), bool)
-        mask[step_slots] = True
-        rem = np.zeros((s,), np.int32)
-        for sl in step_slots:
-            req = self.sched.active[sl]
-            rem[sl] = self._eff_max[req.rid] - req.done_tokens
-        self._dispatch_scan(
-            step_slots, jnp.asarray(self._next_tok), self._key,
-            jnp.zeros((s,), bool), jnp.asarray(rem), jnp.asarray(mask),
-            {sl: int(rem[sl]) for sl in step_slots},
-            {sl: int(self._lengths[sl]) for sl in step_slots})
+        with _span("engine.dispatch", program="runahead_horizon"):
+            mask = np.zeros((s,), bool)
+            mask[step_slots] = True
+            rem = np.zeros((s,), np.int32)
+            for sl in step_slots:
+                req = self.sched.active[sl]
+                rem[sl] = self._eff_max[req.rid] - req.done_tokens
+            self._dispatch_scan(
+                step_slots, jnp.asarray(self._next_tok), self._key,
+                jnp.zeros((s,), bool), jnp.asarray(rem), jnp.asarray(mask),
+                {sl: int(rem[sl]) for sl in step_slots},
+                {sl: int(self._lengths[sl]) for sl in step_slots})
         return []
 
     def _chain_dispatch(self, live: list[int]) -> None:
@@ -1146,9 +1187,10 @@ class EngineCore:
         host reconciles the previous block's events."""
         blk = self._inflight
         tok, key, done, rem = blk["carry"]
-        self._dispatch_scan(live, tok, key, done, rem, blk["mask"],
-                            {sl: blk["ahead_rem"][sl] for sl in live},
-                            {sl: blk["opt_len"][sl] for sl in live})
+        with _span("engine.dispatch", program="runahead_horizon"):
+            self._dispatch_scan(live, tok, key, done, rem, blk["mask"],
+                                {sl: blk["ahead_rem"][sl] for sl in live},
+                                {sl: blk["opt_len"][sl] for sl in live})
 
     def _dispatch_scan(self, slots, tok, key, done, rem, mask,
                        host_rem: dict, host_len: dict) -> None:
@@ -1178,8 +1220,7 @@ class EngineCore:
             "ahead_rem": {sl: max(host_rem[sl] - h, 0) for sl in slots},
             "opt_len": {sl: host_len[sl] + min(h, host_rem[sl])
                         for sl in slots},
-            "carry": (tok, key, done, rem), "mask": mask,
-            "t0": t0, "t_disp": time.monotonic(),
+            "carry": (tok, key, done, rem), "mask": mask, "t0": t0,
         }
 
     def _advance_runahead(self) -> list[TokenEvent]:
@@ -1195,25 +1236,30 @@ class EngineCore:
                     if old["ahead_rem"][sl] > 0
                     and sl in self.sched.active]
             if live:
-                g = self.layout.page_size
-                opt = self._lengths.copy()
-                for sl in live:
-                    opt[sl] = old["opt_len"][sl]
-                dead = [sl for sl in self.sched.active if sl not in live]
-                stalled = set(self.sched.ensure_pages(
-                    opt, skip=dead,
-                    spans={sl: self.runahead for sl in live}))
-                ok = not stalled and all(
-                    self.sched.alloc.slot_pages(sl) * g >=
-                    old["opt_len"][sl] + min(self.runahead,
-                                             old["ahead_rem"][sl])
-                    for sl in live)
-                if ok:
-                    self._chain_dispatch(live)
-                    chained = True
+                with _span("engine.decode", active=len(live)):
+                    chained = self._chain(old, live)
         if not chained:
             self._inflight = None
         return self._reconcile_block(old)
+
+    def _chain(self, old: dict, live: list[int]) -> bool:
+        """Reserve the pages of another horizon for the ``live`` slots
+        and, when every one has them, chain it; False leaves the
+        pipeline to drain."""
+        g = self.layout.page_size
+        opt = self._lengths.copy()
+        for sl in live:
+            opt[sl] = old["opt_len"][sl]
+        dead = [sl for sl in self.sched.active if sl not in live]
+        stalled = set(self.sched.ensure_pages(
+            opt, skip=dead, spans={sl: self.runahead for sl in live}))
+        ok = not stalled and all(
+            self.sched.alloc.slot_pages(sl) * g >=
+            old["opt_len"][sl] + min(self.runahead, old["ahead_rem"][sl])
+            for sl in live)
+        if ok:
+            self._chain_dispatch(live)
+        return ok
 
     def _reconcile_horizon(self) -> list[TokenEvent]:
         """Forcibly land the in-flight horizon (cancel or any other
@@ -1229,15 +1275,15 @@ class EngineCore:
         stamps with (span, span_ix) metadata, post-hoc truncation at EOS
         or budget, finish + page reclamation on the first host step
         after the block lands."""
+        with _span("engine.runahead.land", horizon=self.runahead_horizons,
+                   slots=len(blk["slots"])):
+            return self._land(blk)
+
+    def _land(self, blk: dict) -> list[TokenEvent]:
         sched, h = self.sched, self.runahead
-        t_sync = time.monotonic()
-        gap = t_sync - blk["t_disp"]   # host work overlapped with device
-        self._overlap_s += gap
-        self._gap_ewma = gap if self._gap_ewma is None else \
-            0.8 * self._gap_ewma + 0.2 * gap
-        toks = np.asarray(blk["toks"])  # sync: horizon block lands — one fetch per H tokens
+        with self._sync("horizon"):
+            toks = np.asarray(blk["toks"])  # sync: horizon block lands — one fetch per H tokens
         now = time.monotonic()
-        self._sync_wait_s += now - t_sync
         # wall time is partitioned across pipelined horizons: each bills
         # from the later of its dispatch and the previous landing
         step_s = now - max(blk["t0"], self._land_t)
@@ -1338,32 +1384,35 @@ class EngineCore:
         cache equals the vanilla one bitwise (spec/verify.py)."""
         sched, g = self.sched, self.layout.page_size
         s = self.layout.slots
-        # bucket the span width to this step's longest draft (pow2-ish,
-        # one compile per bucket): a step where every proposer came back
-        # short doesn't pay for k+1 verify positions
-        q = self._spec_q(1 + max(len(drafts.get(sl, ()))
-                                 for sl in step_slots))
-        mask = np.zeros((s,), bool)
-        mask[step_slots] = True
-        toks = np.zeros((s, q), np.int32)
-        toks[:, 0] = self._next_tok
-        dlen = np.zeros((s,), np.int32)
-        for sl in step_slots:
-            d = drafts.get(sl, [])
-            toks[sl, 1:1 + len(d)] = d
-            dlen[sl] = len(d)
-        # width must cover every span position; span pages the scheduler
-        # couldn't (or didn't need to) allocate resolve to the scratch
-        # page, touched only by the discarded verify copy
-        w = self._step_width(
-            max((int(self._lengths[sl]) + q - 1) // g + 1
-                for sl in step_slots))
-        t0 = time.monotonic()
-        preds, n_acc, self.state = self._verify(
-            self.params, self.state, jnp.asarray(toks), jnp.asarray(dlen),
-            sched.alloc.table()[:, :w], jnp.asarray(mask))
-        preds = np.asarray(preds)   # sync: verify-span argmax block
-        n_acc = np.asarray(n_acc)   # sync: verify-span accept counts
+        with _span("engine.dispatch", program="verify"):
+            # bucket the span width to this step's longest draft
+            # (pow2-ish, one compile per bucket): a step where every
+            # proposer came back short doesn't pay for k+1 verify positions
+            q = self._spec_q(1 + max(len(drafts.get(sl, ()))
+                                     for sl in step_slots))
+            mask = np.zeros((s,), bool)
+            mask[step_slots] = True
+            toks = np.zeros((s, q), np.int32)
+            toks[:, 0] = self._next_tok
+            dlen = np.zeros((s,), np.int32)
+            for sl in step_slots:
+                d = drafts.get(sl, [])
+                toks[sl, 1:1 + len(d)] = d
+                dlen[sl] = len(d)
+            # width must cover every span position; span pages the
+            # scheduler couldn't (or didn't need to) allocate resolve to
+            # the scratch page, touched only by the discarded verify copy
+            w = self._step_width(
+                max((int(self._lengths[sl]) + q - 1) // g + 1
+                    for sl in step_slots))
+            t0 = time.monotonic()
+            preds, n_acc, self.state = self._verify(
+                self.params, self.state, jnp.asarray(toks),
+                jnp.asarray(dlen), sched.alloc.table()[:, :w],
+                jnp.asarray(mask))
+        with self._sync("verify"):
+            preds = np.asarray(preds)   # sync: verify-span argmax block
+            n_acc = np.asarray(n_acc)   # sync: verify-span accept counts
         step_s = time.monotonic() - t0
         self.clock += step_s
         self.decode_steps += 1
@@ -1438,6 +1487,9 @@ class EngineCore:
             "p50_latency_s": pct(50),
             "p99_latency_s": pct(99),
             "decode_steps": self.decode_steps,
+            "prefill_chunks": self.prefill_chunks,
+            # host time blocked on the device, every serving-path sync
+            "host_sync_s": self.host_sync_s,
             "decode_step_s_mean": float(np.mean(step_times)) if step_times
             else 0.0,
             "decode_step_s_p50": float(np.median(step_times)) if step_times
@@ -1479,18 +1531,10 @@ class EngineCore:
         if self.chaos is not None:
             res["chaos"] = self.chaos.stats()
         if self.runahead > 1:
-            # host-vs-device attribution for the async pipeline: the
-            # dispatch-gap EWMA is host time per horizon overlapped with
-            # device compute; sync_wait is what the host still spends
-            # blocked on landing blocks (the residual per-token sync
-            # cost the run-ahead path exists to amortize)
             res["runahead"] = {
                 "h": self.runahead,
                 "horizons": self.runahead_horizons,
                 "tokens": self.runahead_tokens,
-                "dispatch_gap_ewma_s": self._gap_ewma or 0.0,
-                "host_overlap_s": self._overlap_s,
-                "sync_wait_s": self._sync_wait_s,
             }
         if self.spec is not None:
             res["spec"] = {
