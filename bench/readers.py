@@ -2,12 +2,10 @@
 ``bench/metrics/`` names its layer, unit and the end-to-end metric it
 moves, and calls one of these on the run (``run["record"]``, the host
 clock's record of the window; ``run["trace"]``, the reduced device trace
-or None; ``run.get("engine")``, the reduced engine spans and programs or
-None; ``run["conf"]``, ``run["peaks"]``). A reader that finds nothing to
+or None; ``run["conf"]``, ``run["peaks"]``). A reader that finds nothing to
 read returns None, and the metric is left out of the result line."""
 from __future__ import annotations
 
-from bench.trace import engine as tengine
 from bench.yardstick import work
 
 
@@ -69,39 +67,3 @@ def mfu(run):
     f = work.model_flops(run["conf"]["model"], r["decode_contexts"],
                          r["chunks"])
     return 100.0 * f / (tr["window_s"] * run["peaks"]["bf16_flops"])
-
-
-def _not_sync(label: str) -> bool:
-    return label.split(":", 1)[0] != tengine.SYNC
-
-
-def engine_idle(run):
-    """Share of the traced window in which the device ran nothing while
-    the host was in an engine span other than ``engine.sync``: the device
-    waiting on the engine's own host work."""
-    eng = run.get("engine")
-    if not eng or not eng["devices"] or eng["window_s"] <= 0:
-        return None
-    t = sum(s for n, s in eng["idle"] if n != tengine.NONE and _not_sync(n))
-    return t / (eng["window_s"] * eng["devices"])
-
-
-def engine_host_ms(run):
-    """Self time of the engine's spans other than ``engine.sync`` in the
-    traced window, over the decode micro-steps run in it."""
-    eng = run.get("engine")
-    steps = run["record"]["decode_steps"]
-    if not eng or not steps:
-        return None
-    t = sum(v["s"] for n, v in eng["spans"].items() if _not_sync(n))
-    return 1e3 * t / steps
-
-
-def prefill_chunk_ms(run):
-    """Device time of the ``jit_prefill_chunk`` programs in the traced
-    window over their number."""
-    eng = run.get("engine")
-    p = eng and eng["programs"].get(tengine.CHUNK_PROGRAM)
-    if not p or not p["n"]:
-        return None
-    return 1e3 * p["s"] / p["n"]

@@ -205,22 +205,12 @@ def run(args, root: str = ROOT, require_tpu: bool = True,
                               ("paged_prefill", rec["prefill_tokens"])) if n]
         tr = treduce.reduce_dir(trace_dir,
                                 conf["model"]["num_hidden_layers"], ran)
-        # the engine's spans and named programs (None from a program
-        # that has neither)
-        from bench.trace import engine as tengine
-        run_info["engine"] = eng = tengine.reduce_dir(
-            trace_dir, steps=bool(rec["decode_steps"]
-                                  or rec["prefill_tokens"]),
-            chunks=bool(rec["prefill_tokens"]))
         shutil.rmtree(trace_dir, ignore_errors=True)
         run_info["trace"] = tr
         result["device"]["busy_s"] = tr["busy_s"]
         result["device"]["window_s"] = tr["window_s"]
         result["breakdown"] = {"device_ops": tr["top_ops"][:10],
                                "idle_gaps": tr["idle_gaps"][:10]}
-        if eng is not None:
-            # every entry (under 20), so that they add up to the idle time
-            result["breakdown"]["engine_idle"] = eng["idle"]
         for m in manifest.per_layer(man, cell["name"]):
             v = manifest.metric(root, m["name"]).read(run_info)
             if v is not None:

@@ -17,7 +17,9 @@ each program run ``jit_<name>(<hash>)``.
 
 Events are flattened to ``(plane, line, label, start_ns, duration_ns)``
 as in :mod:`bench.trace.reduce`, whose idle intervals this reduction
-takes, so the tests lay events out by hand.
+takes, so the tests lay events out by hand. :func:`readings` turns a
+reduction into three numbers per window, and ``bench/engine_readings.py``
+prints them for one traced run of a cell.
 """
 from __future__ import annotations
 
@@ -191,3 +193,32 @@ def reduce_dir(trace_dir: str, steps: bool = False, chunks: bool = False):
     if not pbs:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     return reduce(events_from_xplane(pbs[0]), steps, chunks)
+
+
+def _not_sync(label: str) -> bool:
+    return label.split(":", 1)[0] != SYNC
+
+
+def readings(red, decode_steps: int) -> dict:
+    """Of a reduction (None reads nothing): ``engine_idle``, the share of
+    the window in which the device ran nothing while the host was in an
+    engine span other than ``engine.sync`` (the device waiting on the
+    engine's own host work); ``engine_host_ms``, the self time of the
+    engine's spans other than ``engine.sync`` over the window's decode
+    micro-steps; ``prefill_chunk_ms``, the device time of the
+    ``jit_prefill_chunk`` programs over their number. A number with
+    nothing to read is None."""
+    out = dict.fromkeys(("engine_idle", "engine_host_ms",
+                         "prefill_chunk_ms"))
+    if red is None:
+        return out
+    if red["devices"] and red["window_s"] > 0:
+        t = sum(s for n, s in red["idle"] if n != NONE and _not_sync(n))
+        out["engine_idle"] = t / (red["window_s"] * red["devices"])
+    if decode_steps:
+        t = sum(v["s"] for n, v in red["spans"].items() if _not_sync(n))
+        out["engine_host_ms"] = 1e3 * t / decode_steps
+    p = red["programs"].get(CHUNK_PROGRAM)
+    if p and p["n"]:
+        out["prefill_chunk_ms"] = 1e3 * p["s"] / p["n"]
+    return out

@@ -1,7 +1,9 @@
 """The engine-span reduction (``bench/trace/engine.py``): device idle time
 booked to the innermost engine span, self times, named programs and the
-three metrics that read them, on events laid out by hand as a TPU trace
-lays them out, and the labels read back from a real profiler trace."""
+three readings of them, on events laid out by hand as a TPU trace lays
+them out, the labels read back from a real profiler trace, and
+``bench/engine_readings.py``, which reduces a run's trace."""
+import argparse
 import glob
 import os
 import sys
@@ -12,7 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import readers  # noqa: E402
+from bench import engine_readings  # noqa: E402
 from bench.trace import engine as eng  # noqa: E402
 from bench.trace import reduce as tr  # noqa: E402
 
@@ -95,13 +97,59 @@ def test_self_times_and_programs():
 
 
 def test_the_metrics_read_the_reduction():
-    run = {"engine": eng.reduce(_events(), steps=True, chunks=True),
-           "record": {"decode_steps": 5}}
-    # idle under engine spans, sync and no span left out: 23 of 100 ms
-    assert readers.engine_idle(run) == pytest.approx(0.23)
+    got = eng.readings(eng.reduce(_events(), steps=True, chunks=True), 5)
+    # idle under engine spans, sync and no span left out: 23 of 100 ms;
     # 90 ms of spans less 39 of sync, over 5 micro-steps
-    assert readers.engine_host_ms(run) == pytest.approx(51 / 5)
-    assert readers.prefill_chunk_ms(run) == pytest.approx(20.0)
+    assert got == {"engine_idle": pytest.approx(0.23),
+                   "engine_host_ms": pytest.approx(51 / 5),
+                   "prefill_chunk_ms": pytest.approx(20.0)}
+
+
+@pytest.mark.parametrize("drop,steps,unread", [
+    ("jit_prefill_chunk", 5, "prefill_chunk_ms"),
+    (OPS, 5, "engine_idle"),
+    ("", 0, "engine_host_ms")])
+def test_a_reading_with_nothing_to_read_is_none(drop, steps, unread):
+    """No chunk program, no device operation, no decode micro-step: that
+    reading alone is None."""
+    ev = [e for e in _events() if drop not in (e[1], e[2])]
+    got = eng.readings(eng.reduce(ev, steps=True), steps)
+    assert got[unread] is None
+    assert all(v is not None for k, v in got.items() if k != unread)
+
+
+def test_engine_readings_reduces_the_runs_trace(monkeypatch):
+    """The tool reduces the trace the run writes, before the run removes
+    it, with the flags the window's kernels give; the run is traced, and
+    the device reduction is put back after."""
+    calls = []
+
+    def device(d, layers, expect=()):
+        calls.append(("device", d, layers, tuple(expect)))
+        return {"device": True}
+
+    def engine(d, steps=False, chunks=False):
+        calls.append(("engine", d, steps, chunks))
+        return eng.reduce(_events(), steps, chunks)
+
+    def run(args, root, require_tpu, hooks):
+        assert args.trace == 1 and args.workload == "w"
+        hooks["window"](None, {"decode_steps": 5})
+        assert tr.reduce_dir("d", 12, ["paged_decode", "paged_prefill"]) \
+            == {"device": True}
+        return {"correct": True}
+
+    monkeypatch.setattr(tr, "reduce_dir", device)
+    monkeypatch.setattr(eng, "reduce_dir", engine)
+    monkeypatch.setattr(engine_readings.run, "run", run)
+    got = engine_readings.traced(argparse.Namespace(
+        workload="w", seed=1, seconds=1.0), require_tpu=False)
+    assert calls == [("engine", "d", True, True),
+                     ("device", "d", 12, ("paged_decode", "paged_prefill"))]
+    assert tr.reduce_dir is device
+    assert got["result"] == {"correct": True}
+    assert got["engine"]["programs"]["jit_prefill_chunk"]["n"] == 1
+    assert got["readings"]["engine_host_ms"] == pytest.approx(51 / 5)
 
 
 def test_a_span_that_outlives_its_parent_is_cut():
@@ -132,15 +180,14 @@ def test_missing_names_are_an_error():
 def test_a_program_without_spans_or_names_reduces_to_nothing():
     """A program older than the engine's spans and program names (every
     program ``jit__lambda``): nothing to read, so its traced run leaves
-    the three metrics out instead of failing."""
+    the three readings out instead of failing."""
     ev = [(p, l, "jit__lambda" if l == MODS else n, s, d)
           for p, l, n, s, d in _events() if not n.startswith("engine.")]
     got = eng.reduce(ev, steps=True, chunks=True)
     assert got is None
-    run = {"engine": got, "record": {"decode_steps": 5}}
-    assert readers.engine_idle(run) is None
-    assert readers.engine_host_ms(run) is None
-    assert readers.prefill_chunk_ms(run) is None
+    assert eng.readings(got, 5) == {"engine_idle": None,
+                                    "engine_host_ms": None,
+                                    "prefill_chunk_ms": None}
 
 
 def test_labels_read_from_a_profiler_trace(tmp_path):

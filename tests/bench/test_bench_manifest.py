@@ -177,30 +177,3 @@ def test_found_by_name_alone(tmp_path):
     assert {m["name"] for m in manifest.end_to_end(got, "scratch.cell")} == \
         {"ttft_p95_ms", "setup_s"}
     assert manifest.reference(root, conf).score
-
-
-@pytest.mark.parametrize("name,unit,source", [
-    ("engine_idle.batch", "share", "program_span"),
-    ("engine_host_ms.batch", "ms", "program_span"),
-    ("prefill_chunk_ms.batch", "ms", "device_trace")])
-def test_engine_metrics(man, name, unit, source):
-    """The metrics of the engine's spans and named programs: in the
-    engine step's layer, listing both cells, found by name, and left out
-    (not raised) by a run that has no engine reduction to read, as a
-    program without the spans gives."""
-    entry = {m["name"]: m for m in man["per_layer"]}[name]
-    cells = [w["name"] for w in man["workloads"]]
-    assert entry == {"name": name, "unit": unit, "better": "lower",
-                     "source": source,
-                     "layer": "engine step (serve/core.py, "
-                              "models/transformer.py)",
-                     "moves": "output_tok_s", "workloads": cells}
-    for w in cells:
-        assert name in [m["name"] for m in manifest.per_layer(man, w)]
-    mod = manifest.metric(ROOT, name)
-    assert (mod.LAYER, mod.UNIT, mod.MOVES) == (entry["layer"], unit,
-                                               "output_tok_s")
-    record = {"decode_steps": 10}
-    assert mod.read({"record": record, "trace": None}) is None
-    assert mod.read({"record": record, "trace": None, "engine": None}) \
-        is None
