@@ -1,5 +1,5 @@
 """Smoke run of the paged serving path on the TPU: tinyllama-1.1b at its
-published widths, PolarQuant keys, the Pallas LUT kernels.
+published widths, PolarQuant keys, the paged Pallas kernels.
 
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # a (1, 4) mesh against one chip

@@ -11,9 +11,18 @@ quantized page pools *in place* (vLLM-style paged attention):
 
     per (s, h) slot/KV head, for each page n of the slot's table row:
         codes/stats/values  <- pool[table[s, n]]        (index-map walk)
-        scores = LUT(q, codes_n)                        (VPU select-tree)
+        K~     = rho~ (cos, sin)(theta~ - pi)           (page dequant, VPU)
+        scores = q @ K~^T                               (MXU, fp32)
         m, l   = online-softmax update                  (VMEM scratch)
         acc   += exp(s - m) @ V_n                       (MXU)
+
+The page is decoded once per grid step for all ``Qh`` query heads of the
+KV head, by ``polar_attention._dequant_page_keys`` (the page decoder the
+prefill kernel shares), so the VPU work of a step does not grow with the
+query group. The score product runs at ``Precision.HIGHEST``: the MXU's
+split passes keep the scores as accurate as fp32, the precision of the
+angle LUT (``polar_attention._lut_scores_block``), which computes the
+same sum per query head in a select tree of ``2**t_bits`` passes.
 
 Per-slot lengths mask dead pages: grid steps past ``flushed[s] // g``
 contribute nothing, and their index maps *clamp to the slot's last live
@@ -38,16 +47,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.polar_attention import _lut_scores_block, to_f32
+from repro.kernels.polar_attention import _dequant_page_keys, to_f32
 
 Array = jax.Array
 NEG_INF = -1e30
 
 
+def _dot_t_highest(a, b):
+    """a (M, K) @ b (N, K)^T -> (M, N) in fp32 at ``Precision.HIGHEST``:
+    the MXU's split passes keep the product as accurate as fp32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _paged_attn_kernel(table_ref, flushed_ref, q_ref, codes_ref, rs_ref,
                        rz_ref, ts_ref, tz_ref, v_ref, vs_ref, vz_ref,
                        out_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref,
-                       *, r_bits: int, t_bits: int, quantized_values: bool,
+                       *, t_bits: int, quantized_values: bool,
                        page_size: int):
     s, n = pl.program_id(0), pl.program_id(2)
     g = page_size
@@ -59,14 +76,15 @@ def _paged_attn_kernel(table_ref, flushed_ref, q_ref, codes_ref, rs_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                    # (Qh, d)
-    codes = codes_ref[0, 0][None]                          # (1, g, P)
-    scores = _lut_scores_block(
-        q, codes,
+    kx, ky = _dequant_page_keys(
+        codes_ref[0, 0],
         rs_ref[0, 0].astype(jnp.float32),
         rz_ref[0, 0].astype(jnp.float32),
         ts_ref[0, 0].astype(jnp.float32),
-        tz_ref[0, 0].astype(jnp.float32),
-        r_bits, t_bits)                                    # (Qh, g)
+        tz_ref[0, 0].astype(jnp.float32), t_bits)         # (g, P) each
+    p_half = q.shape[1] // 2
+    scores = (_dot_t_highest(q[:, :p_half], kx)
+              + _dot_t_highest(q[:, p_half:], ky))         # (Qh, g)
 
     flushed = flushed_ref[s]
     pos = n * g + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -135,7 +153,7 @@ def polar_paged_decode_grouped(
         return (table_ref[i, jnp.minimum(k, live - 1)], j, 0, 0)
 
     kern = functools.partial(
-        _paged_attn_kernel, r_bits=r_bits, t_bits=t_bits,
+        _paged_attn_kernel, t_bits=t_bits,
         quantized_values=quantized_values, page_size=g)
 
     codes_spec = pl.BlockSpec((1, 1, g, p), page_map)

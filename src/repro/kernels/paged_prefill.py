@@ -23,11 +23,10 @@ The chunk's ``Tc`` query rows ride folded onto the query-head axis
 (``QT = (Hq/Hkv) * Tc`` rows per kv head, row = qh * Tc + t) — the same
 folding ``chunk_prefill_attention`` uses — and a grid axis tiles those
 rows into blocks of at most ``block_rows``, so VMEM use is bounded by the
-block and not by the chunk. Decode scores one query row per head against
-a page, where the angle LUT (``polar_attention._lut_scores_block``)
-beats dequantizing; a chunk scores hundreds of rows against the same
-page, so here each page is dequantized once into fp keys and scored on
-the MXU. The two forms are the same sum,
+block and not by the chunk. Each page is dequantized once into fp keys
+by ``polar_attention._dequant_page_keys``, the page decoder the decode
+kernel shares, and scored on the MXU. The angle LUT
+(``polar_attention._lut_scores_block``) computes the same sum,
 ``sum_j rho_j (q_x[j] cos_j + q_y[j] sin_j)``, in a different order.
 
 Causality needs no masking on the prefix steps (every chunk token sits
@@ -58,19 +57,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.polar_attention import to_f32
+from repro.kernels.polar_attention import _dequant_page_keys, to_f32
 
 Array = jax.Array
 NEG_INF = -1e30
-
-
-def _dequant_page_keys(codes, rs, rz, ts, tz, t_bits: int):
-    """One page's polar codes (g, P) -> fp32 key halves (k_x, k_y), each
-    (g, P): ``rho~ * (cos, sin)(theta~ - pi)`` per channel pair."""
-    codes = codes.astype(jnp.int32)     # the TPU compiler cannot shift uint8
-    rho = ((codes >> t_bits).astype(jnp.float32) + 0.5) * rs + rz
-    theta = ((codes & ((1 << t_bits) - 1)).astype(jnp.float32) + 0.5) * ts + tz
-    return rho * jnp.cos(theta - jnp.pi), rho * jnp.sin(theta - jnp.pi)
 
 
 def _dot_t(a, b):

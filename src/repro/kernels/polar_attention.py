@@ -37,6 +37,15 @@ def to_f32(x):
     return x.astype(jnp.float32)
 
 
+def _dequant_page_keys(codes, rs, rz, ts, tz, t_bits: int):
+    """One page's polar codes (g, P) -> fp32 key halves (k_x, k_y), each
+    (g, P): ``rho~ * (cos, sin)(theta~ - pi)`` per channel pair."""
+    codes = codes.astype(jnp.int32)     # the TPU compiler cannot shift uint8
+    rho = ((codes >> t_bits).astype(jnp.float32) + 0.5) * rs + rz
+    theta = ((codes & ((1 << t_bits) - 1)).astype(jnp.float32) + 0.5) * ts + tz
+    return rho * jnp.cos(theta - jnp.pi), rho * jnp.sin(theta - jnp.pi)
+
+
 def _lut_scores_block(q, codes, rs, rz, ts, tz, r_bits, t_bits):
     """Shared LUT score tile: q (Qh,d) fp32; codes (gb,g,P) -> (Qh, gb*g)."""
     qh, d = q.shape

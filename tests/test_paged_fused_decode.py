@@ -78,6 +78,57 @@ def test_polar_page_native_kernel_parity(backend, value_bits):
                                atol=2e-5, rtol=1e-4)
 
 
+#: (Hkv, query heads per KV head, head_dim) as served
+SERVED_WIDTHS = {"yi-9b": (4, 8, 128), "granite-8b": (8, 4, 128),
+                 "tinyllama-1.1b": (4, 8, 64)}
+
+
+@pytest.mark.parametrize("quantized_values", [False, True],
+                         ids=["fp_values", "uint8_values"])
+@pytest.mark.parametrize("widths", list(SERVED_WIDTHS.values()),
+                         ids=list(SERVED_WIDTHS))
+def test_page_native_kernel_matches_oracle_at_served_widths(
+        widths, quantized_values):
+    """The interpret-mode kernel vs ``ref_polar_paged_decode_attention`` at
+    page 128 and served head widths, the shapes whose page decode and MXU
+    score product the small fixtures above never build. Three slots on a
+    shuffled pool hold 4, 1 and 3 live pages of 4: the one-page slot
+    clamps every later grid step to its only page."""
+    from repro.kernels import ref as R
+    from repro.kernels.paged_decode import polar_paged_decode_grouped
+    hkv, qh, dh = widths
+    gs, n, slots = 128, 4, 3
+    pp, p = slots * n + 1, dh // 2            # pool pages + scratch page
+    ks = jax.random.split(jax.random.PRNGKey(dh + qh), 9)
+
+    def unif(k, shape, lo, hi):
+        return jax.random.uniform(k, shape, minval=lo, maxval=hi)
+
+    q = jax.random.normal(ks[0], (slots, hkv, qh, dh)) * dh ** -0.5
+    codes = jax.random.randint(ks[1], (pp, hkv, gs, p), 0, 256,
+                               jnp.int32).astype(jnp.uint8)
+    stat = (pp, hkv, 1, p)
+    rs, rz = unif(ks[2], stat, 0.05, 0.3), unif(ks[3], stat, 0.0, 0.5)
+    ts, tz = unif(ks[4], stat, 0.3, 0.5), unif(ks[5], stat, -0.2, 0.2)
+    if quantized_values:
+        vals = (jax.random.randint(ks[6], (pp, hkv, gs, dh), 0, 256,
+                                   jnp.int32).astype(jnp.uint8),
+                unif(ks[7], (pp, hkv, gs, 1), 0.005, 0.02),
+                unif(ks[8], (pp, hkv, gs, 1), -2.0, 0.0))
+    else:
+        vals = (jax.random.normal(ks[6], (pp, hkv, gs, dh)), None, None)
+    table = jnp.asarray(np.random.default_rng(dh).permutation(slots * n)
+                        .reshape(slots, n), jnp.int32)
+    flushed = jnp.asarray([4, 1, 3], jnp.int32) * gs
+    got = polar_paged_decode_grouped(q, codes, rs, rz, ts, tz, *vals, table,
+                                     flushed, interpret=True)
+    want = R.ref_polar_paged_decode_attention(
+        q, codes, rs, rz, ts, tz, *vals, table, flushed, r_bits=4, t_bits=4)
+    for x, y in zip(got, want):                      # out, m, l partials
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   atol=2e-5, rtol=1e-4)
+
+
 def test_gathered_backend_still_runs_dense_fused_path():
     """backend="gathered" keeps the PR-2 formulation alive for A/B."""
     cfg = _cfg("polar")

@@ -1,6 +1,8 @@
 """Compile the serving kernels for a described TPU v5e at tinyllama-1.1b's
 published widths (Hkv 4, 8 query heads per KV head, head_dim 64, page 128,
-uint8 codes).
+uint8 codes); the paged decode kernel also at the benchmark cells' widths,
+yi-9b's (Hkv 4, 8 query heads each, head_dim 128) and granite-8b's
+(Hkv 8, 4 query heads each, head_dim 128).
 
 Interpret mode runs the kernel bodies on the CPU but accepts programs the
 TPU compiler refuses: unaligned block shapes, uint8 shifts and casts, more
@@ -51,28 +53,40 @@ def one_chip():
     cc.reset_cache()
 
 
-def _pools(sds, quantized_values):
+def _pools(sds, quantized_values, hkv=HKV, d=D):
     """Shape stand-ins for one layer's page pools: codes, the four polar
     stats, and values (fp rows, or uint8 codes with scale/zero)."""
-    stat = sds((PP, HKV, 1, D // 2), jnp.float32)
-    pools = [sds((PP, HKV, G, D // 2), jnp.uint8), stat, stat, stat, stat]
+    stat = sds((PP, hkv, 1, d // 2), jnp.float32)
+    pools = [sds((PP, hkv, G, d // 2), jnp.uint8), stat, stat, stat, stat]
     if quantized_values:
-        vstat = sds((PP, HKV, G, 1), jnp.float32)
-        return pools + [sds((PP, HKV, G, D), jnp.uint8), vstat, vstat]
-    return pools + [sds((PP, HKV, G, D), jnp.bfloat16), None, None]
+        vstat = sds((PP, hkv, G, 1), jnp.float32)
+        return pools + [sds((PP, hkv, G, d), jnp.uint8), vstat, vstat]
+    return pools + [sds((PP, hkv, G, d), jnp.bfloat16), None, None]
 
 
 def _compiled_text(fn, args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("quantized_values", [False, True],
-                         ids=["fp_values", "uint8_values"])
-def test_paged_decode_compiles_for_v5e(one_chip, quantized_values):
+def _decode_cases():
+    """(arch, quantized_values) cases; tinyllama's, the file's own widths,
+    are named by the value format alone."""
+    for arch in ("tinyllama-1.1b", "yi-9b", "granite-8b"):
+        for qv in (False, True):
+            fmt = "uint8_values" if qv else "fp_values"
+            yield pytest.param(arch, qv, id=fmt if arch == CFG.name
+                               else f"{arch}-{fmt}")
+
+
+@pytest.mark.parametrize("arch,quantized_values", list(_decode_cases()))
+def test_paged_decode_compiles_for_v5e(one_chip, arch, quantized_values):
+    cfg = get_config(arch)
+    hkv, qpk, d = cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim
+    assert cfg.quant.group_size == G
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
                                                  sharding=one_chip)
-    args = [sds((SLOTS, HKV, QPK, D), jnp.float32),
-            *_pools(sds, quantized_values),
+    args = [sds((SLOTS, hkv, qpk, d), jnp.float32),
+            *_pools(sds, quantized_values, hkv, d),
             sds((SLOTS, N), jnp.int32), sds((SLOTS,), jnp.int32)]
 
     def step(q, c, rs, rz, ts, tz, v, vs, vz, table, flushed):
